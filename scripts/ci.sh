@@ -12,8 +12,6 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=cpu
-# hypothesis-based suites importorskip when dev deps are absent
-python -m pip install -q -r requirements-dev.txt 2>/dev/null || true
 
 PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" python -m pytest -x -q "$@"
 

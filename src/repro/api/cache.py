@@ -32,6 +32,7 @@ and keeps paged decode bit-identical to the dense reference.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import partial
 from typing import Any, List, Optional, Union
 
 import jax
@@ -79,13 +80,16 @@ class CacheSpec:
         return CacheSpec(kind=spec, page_size=page)
 
 
-def insert_row_pytree(big, small, row: int, batch: int):
+@partial(jax.jit, static_argnames=("batch",), donate_argnums=(0,))
+def insert_row_pytree(big, small, row, batch: int):
     """Insert batch-1 pytree ``small`` as row ``row`` of batched ``big``.
 
     The historical ``DecodeSession`` row-insert: the batch axis of each leaf
     is found by matching ``batch`` vs 1 dims; batch-independent leaves (PRNG
     key) pass through. Lives here so both the session (non-cache state) and
-    ``DenseKVCache.insert_row`` share one definition.
+    ``DenseKVCache.insert_row`` share one definition. ``big`` is donated and
+    updated in place (an eager update would copy the whole batched cache);
+    callers must drop their reference to it.
     """
     def one(b, s):
         axis = None
@@ -330,10 +334,15 @@ class PagedKVCache(KVCacheManager):
                                     for _ in range(self.pages_per_row)]
         return np.asarray(self._row_pages[row], np.int32)
 
-    def _scatter_entry(self, pool_entry, dense_entry, slots):
+    @staticmethod
+    @partial(jax.jit, donate_argnums=(0,))
+    def _scatter_entry(pool_entry, dense_entry, slots):
         """Write a dense cache entry's first ``len(slots)`` logical slots
         into the pool. pool leaves: (reps, NP, ps, ...); dense leaves:
-        (reps, B?, S, ...) pre-indexed to match ``slots``'s batch shape."""
+        (reps, B?, S, ...) pre-indexed to match ``slots``'s batch shape.
+        The pool is donated and written in place — an eager scatter would
+        copy the whole pool, which a full-size model's memory cannot hold
+        next to the live one."""
         def one(pool, src):
             flat = pool.reshape((pool.shape[0], pool.shape[1] * pool.shape[2])
                                 + pool.shape[3:])

@@ -64,10 +64,6 @@ from repro.api.types import StepResult
 _NO_BUDGET = np.iinfo(np.int64).max
 _DEV_NO_BUDGET = np.iinfo(np.int32).max     # device-carry budget cap
 
-# back-compat alias: the row-insert helper moved to repro.api.cache so the
-# cache managers share it
-_insert_row = insert_row_pytree
-
 
 @dataclass
 class MegatickHandle:
@@ -101,7 +97,9 @@ class Engine:
         # tensor-parallel serving (DESIGN.md §9): a 2-D ("data","model") mesh
         # pins the weights with the Megatron-role specs and threads a static
         # ShardCtx into the jitted steps (sharded exit-gate verify). A mesh
-        # whose 'model' extent is 1 degenerates to the unsharded path.
+        # whose 'model' extent is 1 runs the unsharded step, but its weights
+        # and state are still pinned to that mesh's device — that is what
+        # puts each one-chip replica of a ReplicaPool on its own chip.
         self.mesh = mesh
         self.policy = policy
         self.shard = None
@@ -116,7 +114,7 @@ class Engine:
         from repro import quant as quant_lib
         self.quant_spec = quant_lib.QuantSpec.resolve(quant)
         self.qw = quant_lib.quantize_params(params, sw, self.quant_spec)
-        if self.shard is not None:
+        if mesh is not None:
             from repro.sharding import serving as shard_serving
             ps, ss, qs = shard_serving.engine_shardings(
                 model, mesh, policy, self.params, self.sw, self.qw)
@@ -174,18 +172,36 @@ class Engine:
         return self.strategy.emit_width(self.model)
 
     def shard_state(self, state, cache_mgr=None):
-        """Pin a ``DecodeState`` to the engine's mesh layout (no-op when
-        unsharded). Sessions call this wherever a state is (re)built from
+        """Pin a ``DecodeState`` to the engine's mesh layout (no-op without
+        a mesh). Sessions call this wherever a state is (re)built from
         host values — empty-state alloc, whole-batch prefill, row insert,
         restore — so the jitted step always sees one stable input layout
         (drifting shardings would fork the jit cache per layout)."""
-        if self.shard is None:
+        if self.mesh is None:
             return state
         from repro.sharding import policies as pol
         from repro.sharding import serving as shard_serving
         specs = shard_serving.decode_state_specs(
             self.model, self.mesh, self.policy, state, cache_mgr=cache_mgr)
         return jax.device_put(state, pol.named(self.mesh, specs))
+
+    def empty_state(self, cache_mgr: KVCacheManager, batch: int,
+                    max_seq: int, prng):
+        """An all-empty ``batch``-slot decode state in the engine's layout.
+        Under a mesh it is allocated directly in its shards: a full-size
+        paged pool would not fit one device on its way to being split."""
+        def build():
+            return self.strategy.empty_state(self.model, self.sw, batch,
+                                             max_seq, prng=prng,
+                                             cache=cache_mgr.empty_cache())
+        if self.mesh is None:
+            return build()
+        from repro.sharding import policies as pol
+        from repro.sharding import serving as shard_serving
+        specs = shard_serving.decode_state_specs(
+            self.model, self.mesh, self.policy, jax.eval_shape(build),
+            cache_mgr=cache_mgr)
+        return jax.jit(build, out_shardings=pol.named(self.mesh, specs))()
 
     def prefill_weights(self):
         """(params, sw) the prefill/admission paths consume.
@@ -276,12 +292,8 @@ class DecodeSession:
                 max_seq = engine.model.run.serve.max_seq_len
                 self._max_seq = max_seq
             self.cache_mgr = self._make_manager(batch, max_seq)
-            self._state = engine.shard_state(
-                engine.strategy.empty_state(
-                    engine.model, engine.sw, batch, max_seq,
-                    prng=jax.random.PRNGKey(prng_seed),
-                    cache=self.cache_mgr.empty_cache()),
-                self.cache_mgr)
+            self._state = engine.empty_state(self.cache_mgr, batch, max_seq,
+                                             jax.random.PRNGKey(prng_seed))
             self._alloc_bookkeeping(batch, live=False)
 
     def _make_manager(self, batch: int, max_seq: int) -> KVCacheManager:

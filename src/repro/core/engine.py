@@ -121,13 +121,20 @@ class StepInfo(NamedTuple):
 
 
 def init_specee(model: Model, key) -> SpecEEWeights:
+    """Seeded SpecEE weights: the draft layer in ``cfg.dtype`` (drawn and
+    cast in one jit, like ``Model.init``), the predictor bank in fp32."""
     spec = model.run.specee
-    k1, k2 = jax.random.split(key)
-    return SpecEEWeights(
-        draft=draft_lib.init_draft(model.cfg, k1),
-        predictors=pred_lib.init_predictors(spec, model.num_exit_points, k2),
-        offline_mask=jnp.ones((model.num_exit_points,), bool),
-    )
+    dt = common.dtype_of(model.cfg.dtype)
+
+    def draw(key):
+        k1, k2 = jax.random.split(key)
+        return SpecEEWeights(
+            draft=common.cast_tree(draft_lib.init_draft(model.cfg, k1), dt),
+            predictors=pred_lib.init_predictors(spec, model.num_exit_points,
+                                                k2),
+            offline_mask=jnp.ones((model.num_exit_points,), bool),
+        )
+    return jax.jit(draw)(key)
 
 
 def init_decode_state(model: Model, params: Params,
@@ -245,7 +252,7 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
             live = ~exited
             h_new, seg_cache = model.run_unit(params, seg, u, h, seg_cache,
                                               pos, live_mask=live,
-                                              pages=pages)
+                                              pages=pages, shard=shard)
             h = jnp.where(exited[:, None], h, h_new)
             ep = ep_base + u                                   # global exit pt
 
@@ -258,7 +265,7 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
                 # predictor fused ("kernel"/"xla") or the four-op reference
                 p_exit, probs, _ = gate_lib.exit_gate(
                     hn, lm_w, spec_ids, prev_probs, predictors, ep,
-                    impl=gate_impl, spec_head_kernel=sh_kernel)
+                    impl=gate_impl, spec_head_kernel=sh_kernel, shard=shard)
                 would = act & (p_exit > thresh)
 
                 def verify(args2):
@@ -467,7 +474,8 @@ def tree_decode_step(model: Model, params: Params, sw: SpecEEWeights,
                 hn = model.final_norm(params, h).reshape(B * N, -1)
                 feats, probs = feat_lib.extract_features(
                     hn, lm_w, child_toks.reshape(B * N, k),
-                    prev_probs.reshape(B * N, k), use_kernel=sh_kernel)
+                    prev_probs.reshape(B * N, k), use_kernel=sh_kernel,
+                    shard=shard)
                 feats = feats.reshape(B, N, -1)
                 probs = probs.reshape(B, N, k)
                 # hyper-token merge: one predictor eval per root→leaf path
@@ -476,7 +484,7 @@ def tree_decode_step(model: Model, params: Params, sw: SpecEEWeights,
                     jnp.full((path_nodes.shape[0],), path_nodes.shape[1]))
                 p_exit = pred_lib.apply_predictor_banked(
                     predictors, ep, pf,
-                    use_kernel=pred_kernel)                    # (B, P)
+                    use_kernel=pred_kernel, shard=shard)       # (B, P)
                 fire = jnp.max(p_exit, axis=1) > thresh     # best path fires
                 newly = act & fire
                 exit_pt = jnp.where(newly, ep, exit_pt)
@@ -721,7 +729,8 @@ def dense_decode_step(model: Model, params: Params,
     """
     params, lm_w, _ = _apply_qw(params, sw, qw)
     pos_before = state.cache["len"]
-    h, cache = model.decode_step_hidden(params, state.last_token, state.cache)
+    h, cache = model.decode_step_hidden(params, state.last_token, state.cache,
+                                        shard=shard)
     if temperature > 0.0:
         from repro.serving.sampler import row_keys, sample_rows
         keys = row_keys(state.prng, pos_before, state.last_token)

@@ -47,17 +47,18 @@ def spec_logits_ref(hn: jnp.ndarray, lm_head,
 
 def extract_features(hn: jnp.ndarray, lm_head,
                      spec_ids: jnp.ndarray, prev_probs: jnp.ndarray,
-                     use_kernel: bool = False
+                     use_kernel: bool = False, shard=None
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Compute the 3k feature vector for one exit point.
 
     hn: (B, D) — final-normed hidden state of the current layer
     prev_probs: (B, k) — local probabilities at the previous exit point
+    shard: optional ShardCtx for the spec-head kernel under a mesh
     Returns (features (B, 3k) fp32, local_probs (B, k) fp32).
     """
     if use_kernel:
         from repro.kernels.spec_head import ops as sh_ops
-        logits, probs = sh_ops.spec_head(hn, lm_head, spec_ids)
+        logits, probs = sh_ops.spec_head(hn, lm_head, spec_ids, shard=shard)
     else:
         logits = spec_logits_ref(hn, lm_head, spec_ids)
         probs = jax.nn.softmax(logits, axis=-1)

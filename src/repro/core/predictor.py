@@ -70,19 +70,22 @@ def predictor_at(stacked: Params, idx: jnp.ndarray) -> Params:
 
 def apply_predictor_banked(stacked: Params, idx: jnp.ndarray,
                            features: jnp.ndarray,
-                           use_kernel: bool = False) -> jnp.ndarray:
+                           use_kernel: bool = False, shard=None
+                           ) -> jnp.ndarray:
     """Single entry point for a stacked-bank predictor evaluation.
 
     ``use_kernel=True`` routes the bank ``dynamic_index`` + the fused 2-layer
     Pallas MLP through one jit (``repro.kernels.predictor_mlp.ops``); falls
     back to the reference path for non-2-layer banks (DSE sweeps).
+    ``shard``: optional ShardCtx for the kernel under a mesh.
     features: (..., feature_dim) -> exit probability (...,).
     """
     if use_kernel and len(stacked["layers"]) == 2:
         from repro.kernels.predictor_mlp.ops import predictor_mlp_at
         lead = features.shape[:-1]
         flat = features.reshape(-1, features.shape[-1])
-        return predictor_mlp_at(flat, stacked, idx).reshape(lead)
+        return predictor_mlp_at(flat, stacked, idx,
+                                shard=shard).reshape(lead)
     return apply_predictor(predictor_at(stacked, idx), features)
 
 

@@ -29,10 +29,3 @@ def interpret_default() -> bool:
     """Pallas interpret mode: True off-TPU (CPU CI), False on real hardware."""
     return not on_tpu()
 
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams`` (named ``TPUCompilerParams``
-    on jax<=0.4.x). Every kernel's ``compiler_params=`` goes through here."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -118,7 +118,7 @@ def decode_attention_fwd(q: jnp.ndarray, k_cache: jnp.ndarray,
     kt = jnp.moveaxis(k_cache, 2, 1)                         # (B,KVH,S,hd)
     vt = jnp.moveaxis(v_cache, 2, 1)
 
-    from repro.kernels import interpret_default, tpu_compiler_params
+    from repro.kernels import interpret_default
     kernel = functools.partial(_kernel, scale=scale, block_k=block_k,
                                window=window, nk=nk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -144,7 +144,7 @@ def decode_attention_fwd(q: jnp.ndarray, k_cache: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, n_rep, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_default(),
         name="specee_decode_attention",
@@ -225,7 +225,7 @@ def paged_decode_attention_fwd(q: jnp.ndarray, k_pool: jnp.ndarray,
     kt = jnp.moveaxis(k_pool, 2, 1)                          # (NP,KVH,ps,hd)
     vt = jnp.moveaxis(v_pool, 2, 1)
 
-    from repro.kernels import interpret_default, tpu_compiler_params
+    from repro.kernels import interpret_default
 
     def kv_page(b, g, pi, lens, tbl):
         # pages beyond the valid prefix are dead (pl.when masks compute);
@@ -271,7 +271,7 @@ def paged_decode_attention_fwd(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, n_rep, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_default(),
         name=name,

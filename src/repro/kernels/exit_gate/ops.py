@@ -34,10 +34,10 @@ import jax.numpy as jnp
 from repro.kernels import on_tpu
 from repro.kernels.exit_gate import ref as gate_ref
 from repro.kernels.exit_gate import tuning
-from repro.kernels.exit_gate.exit_gate import (argmax_verify_fused,
-                                               argmax_verify_fused_q,
+from repro.kernels.exit_gate.exit_gate import (_pick_vocab_block,
+                                               argmax_verify_fused,
                                                exit_gate_fused,
-                                               topk_verify_fused_q)
+                                               topk_verify_fused)
 from repro.quant import QTensor, unpack_int4
 
 IMPLS = (None, "auto", "kernel", "xla", "ref")
@@ -77,17 +77,20 @@ def _index_bank(predictors, ep):
     return predictor_at(predictors, ep)
 
 
-@partial(jax.jit, static_argnames=("impl", "spec_head_kernel", "block_d"))
+@partial(jax.jit,
+         static_argnames=("impl", "spec_head_kernel", "block_d", "shard"))
 def exit_gate(hn: jnp.ndarray, lm_head, spec_ids: jnp.ndarray,
               prev_probs: jnp.ndarray, predictors, ep: jnp.ndarray,
               impl: Optional[str] = None, spec_head_kernel: bool = False,
-              block_d: int = 512
+              block_d: int = 512, shard=None
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused exit decision for one exit point.
 
     hn: (B, D) final-normed hidden; lm_head: (D, V); spec_ids: (B, k) int32;
     prev_probs: (B, k); predictors: stacked bank (every leaf (E, ...));
-    ep: scalar int32 exit-point index.
+    ep: scalar int32 exit-point index. ``shard``: optional ShardCtx — the
+    gate's kernels then run whole on every device (the gate reads k columns
+    per row; it is replicated work under tensor parallelism).
 
     Returns (p_exit (B,), local_probs (B, k), logits (B, k)), all fp32.
     """
@@ -103,19 +106,19 @@ def exit_gate(hn: jnp.ndarray, lm_head, spec_ids: jnp.ndarray,
         from repro.kernels.predictor_mlp import ops as pm_ops
         from repro.kernels.spec_head import ops as sh_ops
         logits, probs = sh_ops.spec_head(hn, lm_head, spec_ids,
-                                         block_d=block_d)
+                                         block_d=block_d, shard=shard)
         feats = jnp.concatenate(
             [logits, probs, probs - prev_probs.astype(jnp.float32)], axis=-1)
-        return pm_ops.predictor_mlp(feats, pp), probs, logits
+        return pm_ops.predictor_mlp(feats, pp, shard=shard), probs, logits
     if impl == "kernel" and len(layers) == 2:
         return exit_gate_fused(hn, lm_head, spec_ids, prev_probs,
                                layers[0]["w"], layers[0]["b"],
                                layers[1]["w"], layers[1]["b"],
-                               block_d=block_d)
+                               block_d=block_d, shard=shard)
     if impl == "ref" and spec_head_kernel:
         # historical path with the spec_head Pallas kernel selected
         from repro.kernels.spec_head import ops as sh_ops
-        logits, probs = sh_ops.spec_head(hn, lm_head, spec_ids)
+        logits, probs = sh_ops.spec_head(hn, lm_head, spec_ids, shard=shard)
         feats = jnp.concatenate(
             [logits, probs, probs - prev_probs.astype(jnp.float32)], axis=-1)
         return gate_ref.mlp_ref(feats, pp), probs, logits
@@ -128,7 +131,6 @@ def exit_gate(hn: jnp.ndarray, lm_head, spec_ids: jnp.ndarray,
 def _verify_streaming_xla(hn: jnp.ndarray, lm_head: jnp.ndarray,
                           block_v: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """lax.scan over vocab tiles with a running (max, argmax) carry."""
-    from repro.kernels.exit_gate.exit_gate import _pick_vocab_block
     B, D = hn.shape
     V = lm_head.shape[1]
     # same no-copy preference as the kernel: only pad for vocabs where no
@@ -162,7 +164,6 @@ def _q_stream_plan(hn: jnp.ndarray, qt: QTensor, block_v: int):
     quantized streaming-XLA paths. Mirrors the quantized kernels: integer
     codes + per-column scales stream per tile; the scale folds in after
     the dot (exact — scales are column-constant)."""
-    from repro.kernels.exit_gate.exit_gate import _pick_vocab_block
     V = qt.q.shape[-1]
     block_v, pad_v = _pick_vocab_block(V, block_v)
     q = qt.q
@@ -280,7 +281,6 @@ def _local_dtype(impl, hn):
 
 
 def _verify_argmax_sharded(hn, lm_head, shard, impl, block_v, block_d):
-    from repro.sharding import compat
     P = jax.sharding.PartitionSpec
     degree = shard.degree
     wp, width, pad = _shard_pad(lm_head, degree)
@@ -311,8 +311,8 @@ def _verify_argmax_sharded(hn, lm_head, shard, impl, block_v, block_d):
             tok = tok + col0
         return tok[None], val[None]                        # (1, B) partials
 
-    toks, vals = compat.shard_map_unchecked(
-        local, shard.mesh,
+    toks, vals = jax.shard_map(
+        local, mesh=shard.mesh, check_vma=False,
         in_specs=(P(), P(None, shard.axis)),
         out_specs=(P(shard.axis), P(shard.axis)))(hn, wp)
     # merge (P, B) partials: max value wins; equal maxima take the lowest
@@ -324,7 +324,6 @@ def _verify_argmax_sharded(hn, lm_head, shard, impl, block_v, block_d):
 
 
 def _verify_topk_sharded(hn, lm_head, k, shard, impl, block_v, block_d):
-    from repro.sharding import compat
     P = jax.sharding.PartitionSpec
     degree = shard.degree
     wp, width, pad = _shard_pad(lm_head, degree)
@@ -345,7 +344,6 @@ def _verify_topk_sharded(hn, lm_head, k, shard, impl, block_v, block_d):
             vals, sel = jax.lax.top_k(logits, k)
             ids = col0 + sel.astype(jnp.int32)
         elif impl == "kernel":
-            from repro.kernels.exit_gate.exit_gate import topk_verify_fused
             ids, vals = topk_verify_fused(hn, w_local, k, block_v=block_v,
                                           block_d=block_d)
             ids = ids + col0
@@ -358,8 +356,8 @@ def _verify_topk_sharded(hn, lm_head, k, shard, impl, block_v, block_d):
             ids = ids + col0
         return ids[None], vals[None]                      # (1, B, k)
 
-    ids, vals = compat.shard_map_unchecked(
-        local, shard.mesh,
+    ids, vals = jax.shard_map(
+        local, mesh=shard.mesh, check_vma=False,
         in_specs=(P(), P(None, shard.axis)),
         out_specs=(P(shard.axis), P(shard.axis)))(hn, wp)
     # (P, B, k) -> shard-major (B, P·k) pool: within a shard local top-k is
@@ -392,7 +390,7 @@ def verify_argmax(hn: jnp.ndarray, lm_head,
     per-shard partial reduction over the local vocab slice + one tiny merge
     (bit-identical to single-device under any vocab split; see DESIGN.md
     §9). Quantized heads stay on the unsharded path (QTensor tiles ride
-    replicated under a mesh).
+    replicated under a mesh; the kernel then runs whole on every device).
     Returns (token (B,) int32, max logit (B,) fp32).
     """
     impl = _resolve(impl, cpu_default="ref")
@@ -404,8 +402,11 @@ def verify_argmax(hn: jnp.ndarray, lm_head,
             block_v = tuning.best_block_v(hn.shape[1], lm_head.shape[-1],
                                           wbits=lm_head.bits)
         if impl == "kernel":
-            return argmax_verify_fused_q(hn, lm_head, block_v=block_v,
-                                         block_d=block_d)
+            call = partial(argmax_verify_fused, block_v=block_v,
+                           block_d=block_d)
+            if shard is not None:
+                call = shard.replicated(call)
+            return call(hn, lm_head)
         if impl == "xla":
             return _verify_streaming_xla_q(hn, lm_head, block_v)
         return gate_ref.verify_argmax_ref(hn, lm_head,
@@ -428,7 +429,6 @@ def _topk_streaming_xla(hn: jnp.ndarray, lm_head: jnp.ndarray, k: int,
     the earlier (lower-id) entry — bit-matching ``jax.lax.top_k`` on the
     materialized logits.
     """
-    from repro.kernels.exit_gate.exit_gate import _pick_vocab_block
     B, D = hn.shape
     V = lm_head.shape[1]
     block_v, pad_v = _pick_vocab_block(V, block_v)
@@ -485,8 +485,11 @@ def verify_topk(hn: jnp.ndarray, lm_head, k: int,
             block_v = tuning.best_block_v(hn.shape[1], lm_head.shape[-1],
                                           wbits=lm_head.bits)
         if impl == "kernel":
-            return topk_verify_fused_q(hn, lm_head, k, block_v=block_v,
-                                       block_d=block_d)
+            call = partial(topk_verify_fused, k=k, block_v=block_v,
+                           block_d=block_d)
+            if shard is not None:
+                call = shard.replicated(call)
+            return call(hn, lm_head)
         if impl == "xla":
             return _topk_streaming_xla_q(hn, lm_head, k, block_v)
         return gate_ref.verify_topk_ref(hn, lm_head, k,
@@ -494,7 +497,6 @@ def verify_topk(hn: jnp.ndarray, lm_head, k: int,
     if block_v is None:
         block_v = tuning.best_block_v(hn.shape[1], lm_head.shape[1])
     if impl == "kernel":
-        from repro.kernels.exit_gate.exit_gate import topk_verify_fused
         return topk_verify_fused(hn, lm_head, k, block_v=block_v,
                                  block_d=block_d)
     if impl == "xla":

@@ -109,7 +109,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kt = jnp.moveaxis(k, 2, 1)
     vt = jnp.moveaxis(v, 2, 1)
 
-    from repro.kernels import interpret_default, tpu_compiler_params
+    from repro.kernels import interpret_default
     kernel = functools.partial(_kernel, scale=scale, block_q=block_q,
                                block_k=block_k, causal=causal, window=window,
                                nk=nk)
@@ -132,7 +132,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret_default(),

@@ -18,16 +18,23 @@ def _run(x: jnp.ndarray, p) -> jnp.ndarray:
     return predictor_mlp_fused(x, l1["w"], l1["b"], l2["w"], l2["b"])
 
 
-@jax.jit
-def predictor_mlp(x: jnp.ndarray, params) -> jnp.ndarray:
+def _on(shard):
+    # under a mesh the kernel runs whole on every device
+    return _run if shard is None else shard.replicated(_run)
+
+
+@partial(jax.jit, static_argnames=("shard",))
+def predictor_mlp(x: jnp.ndarray, params, shard=None) -> jnp.ndarray:
     """x: (B, F); params: {"layers": [{w,b}, {w,b}]} (repro.core.predictor
     layout, 2-layer case; ``w`` leaves may be ``repro.quant.QTensor`` —
-    dequant then fuses into the kernel tiles) -> (B,) exit probabilities."""
-    return _run(x, params)
+    dequant then fuses into the kernel tiles) -> (B,) exit probabilities.
+    ``shard``: optional ShardCtx."""
+    return _on(shard)(x, params)
 
 
-@jax.jit
-def predictor_mlp_at(x: jnp.ndarray, stacked, ep: jnp.ndarray) -> jnp.ndarray:
+@partial(jax.jit, static_argnames=("shard",))
+def predictor_mlp_at(x: jnp.ndarray, stacked, ep: jnp.ndarray,
+                     shard=None) -> jnp.ndarray:
     """Stacked-bank entry: dynamic-index predictor ``ep`` out of the
     (E, ...)-stacked bank and run the fused MLP, all inside one jit so the
     weight slice feeds the kernel without an HBM round-trip. Quantized
@@ -37,4 +44,4 @@ def predictor_mlp_at(x: jnp.ndarray, stacked, ep: jnp.ndarray) -> jnp.ndarray:
     x: (B, F); stacked: bank with leading (E,) on every leaf."""
     p = jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_index_in_dim(a, ep, 0, False), stacked)
-    return _run(x, p)
+    return _on(shard)(x, p)

@@ -7,23 +7,21 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.spec_head.spec_head import (spec_head_logits,
-                                               spec_head_logits_q)
-from repro.quant import QTensor
+from repro.kernels.spec_head.spec_head import spec_head_logits
 
 
-@partial(jax.jit, static_argnames=("block_d",))
+@partial(jax.jit, static_argnames=("block_d", "shard"))
 def spec_head(hn: jnp.ndarray, lm_head, spec_ids: jnp.ndarray,
-              block_d: int = 512) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              block_d: int = 512, shard=None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused gather + k-GEMM + softmax.
 
     hn: (B, D) final-normed hidden; lm_head: (D, V) array or a
     ``repro.quant.QTensor`` (int8 / packed-int4 codes + per-column scales
-    — dequant fuses into the gather tiles); spec_ids: (B, k) int32.
+    — only the gathered columns are dequantized); spec_ids: (B, k) int32;
+    shard: optional ShardCtx (the kernel then runs on every device).
     Returns (logits (B, k) fp32, local_probs (B, k) fp32).
     """
-    if isinstance(lm_head, QTensor):
-        logits = spec_head_logits_q(hn, lm_head, spec_ids, block_d=block_d)
-    else:
-        logits = spec_head_logits(hn, lm_head, spec_ids, block_d=block_d)
+    logits = spec_head_logits(hn, lm_head, spec_ids, block_d=block_d,
+                              shard=shard)
     return logits, jax.nn.softmax(logits, axis=-1)

@@ -1,22 +1,24 @@
 """Pallas TPU kernel: fused speculative-LM-head (paper §6.2, TPU-adapted).
 
 The paper computes speculative token logits with a cutlass/MegaBlocks group
-GEMM over LM-head *columns* selected by the draft's token ids. On TPU we
-instead drive the column gather from **scalar-prefetched indices in the
-BlockSpec index_map**: grid cell (b, j, d) streams block d of LM-head column
-``spec_ids[b, j]`` into VMEM and accumulates the (1×Dt)·(Dt×1) partial dot
-into the (b, j) output element. HBM traffic is exactly k columns per row
-(k·D·4 bytes) instead of the V·D bytes a full-head matmul would read — the
-10⁴× search-space reduction made physical.
+GEMM over LM-head *columns* selected by the draft's token ids. Here XLA
+gathers those k columns of every row into a ``(k, B, D)`` strip outside the
+kernel (``column_strip``) — HBM traffic is exactly k columns per row, the
+k·D weights instead of the V·D a full-head matmul would read — and the
+kernel reduces the strip against the hidden over D tiles.
 
-Grid: (B, k, D/Dt), Dt = 128-aligned reduction tile. The reduction dimension
-is innermost ("arbitrary" semantics) so the fp32 accumulation in the output
-block is legal on TPU.
+All B rows are blocked together. Mosaic requires each block's last two dims
+to be multiples of (8, 128) or the array's own extent, so a per-row
+``(1, Dt)`` hidden block, a ``(Dt, 1)`` column block or a ``(1, 1)`` output
+block cannot compile at real B; the ``(B, Dt)`` hidden tile, ``(k, B, Dt)``
+strip tile and whole ``(B, k)`` output can.
+
+Grid: (D/Dt,), Dt = 128-aligned reduction tile ("arbitrary" semantics: the
+(B, k) output block stays resident and accumulates in fp32).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,138 +26,84 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(ids_ref, h_ref, w_ref, out_ref):
-    d = pl.program_id(2)
+def fit_block(dim: int, block: int) -> int:
+    """Largest power-of-two fraction of ``block`` that divides ``dim``."""
+    block = min(block, dim)
+    while dim % block:
+        block //= 2
+    return block
 
-    @pl.when(d == 0)
+
+def column_strip(lm_head, spec_ids: jnp.ndarray) -> jnp.ndarray:
+    """The LM-head columns named by ``spec_ids`` (B, k) as a (k, B, D) strip.
+
+    A ``repro.quant.QTensor`` head gathers its integer codes and
+    per-column scales and dequantizes just the strip (``take_columns``):
+    k·B·D values, exactly what the reference gathers.
+    """
+    if hasattr(lm_head, "bits"):
+        from repro.quant import take_columns
+        cols = take_columns(lm_head, spec_ids)              # (D, B, k) fp32
+    else:
+        cols = jnp.take(lm_head, spec_ids, axis=1)          # (D, B, k)
+    return jnp.transpose(cols, (2, 1, 0))
+
+
+def strip_specs(B: int, k: int, D: int, block_d: int):
+    """(nd, [hidden spec, strip spec]) for a grid over nd D tiles."""
+    block_d = fit_block(D, block_d)
+    return D // block_d, [
+        pl.BlockSpec((B, block_d), lambda d: (0, d)),
+        pl.BlockSpec((k, B, block_d), lambda d: (0, 0, d)),
+    ]
+
+
+def accumulate_strip(h_ref, c_ref, acc_ref) -> None:
+    """acc (B, k) += this D tile's per-row dot with each strip column."""
+    k = c_ref.shape[0]
+    h = h_ref[...].astype(jnp.float32)                       # (B, Dt)
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+    part = jnp.zeros(acc_ref.shape, jnp.float32)
+    for j in range(k):                                       # static, k tiny
+        c = c_ref[j].astype(jnp.float32)                     # (B, Dt)
+        part = jnp.where(lane == j,
+                         jnp.sum(h * c, axis=1, keepdims=True), part)
+    acc_ref[...] += part
+
+
+def _kernel(h_ref, c_ref, out_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    h = h_ref[...].astype(jnp.float32)        # (1, Dt)
-    w = w_ref[...].astype(jnp.float32)        # (Dt, 1)
-    out_ref[...] += jnp.dot(h, w, preferred_element_type=jnp.float32)
+    accumulate_strip(h_ref, c_ref, out_ref)
 
 
-def spec_head_logits(hn: jnp.ndarray, lm_head: jnp.ndarray,
-                     spec_ids: jnp.ndarray, block_d: int = 512
-                     ) -> jnp.ndarray:
-    """hn: (B, D); lm_head: (D, V); spec_ids: (B, k) -> logits (B, k) fp32."""
+def spec_head_logits(hn: jnp.ndarray, lm_head, spec_ids: jnp.ndarray,
+                     block_d: int = 512, shard=None) -> jnp.ndarray:
+    """hn: (B, D); lm_head: (D, V) array or ``repro.quant.QTensor``;
+    spec_ids: (B, k) -> logits (B, k) fp32. ``shard``: optional ShardCtx —
+    the gather runs under GSPMD, the kernel on every device."""
+    call = functools.partial(_spec_head_call, block_d=block_d)
+    if shard is not None:
+        call = shard.replicated(call)
+    return call(hn, column_strip(lm_head, spec_ids))
+
+
+def _spec_head_call(hn, strip, *, block_d: int):
     B, D = hn.shape
-    _, V = lm_head.shape
-    k = spec_ids.shape[1]
-    block_d = min(block_d, D)
-    while D % block_d:
-        block_d //= 2
-    nd = D // block_d
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, k, nd),
-        in_specs=[
-            # h row b, reduction tile d
-            pl.BlockSpec((1, block_d), lambda b, j, d, ids: (b, d)),
-            # LM-head column spec_ids[b, j], reduction tile d
-            pl.BlockSpec((block_d, 1), lambda b, j, d, ids: (d, ids[b, j])),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda b, j, d, ids: (b, j)),
-    )
-    from repro.kernels import interpret_default, tpu_compiler_params
+    k = strip.shape[0]
+    nd, in_specs = strip_specs(B, k, D, block_d)
+    from repro.kernels import interpret_default
     fn = pl.pallas_call(
         _kernel,
-        grid_spec=grid_spec,
+        grid=(nd,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((B, k), lambda d: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, k), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret_default(),
         name="specee_spec_head",
     )
-    return fn(spec_ids, hn, lm_head)
-
-
-# ---------------------------------------------------------------------------
-# quantized LM head: int8 / packed-int4 column gather, dequant in-register
-# ---------------------------------------------------------------------------
-def _kernel_q8(ids_ref, h_ref, w_ref, s_ref, out_ref):
-    d = pl.program_id(2)
-
-    @pl.when(d == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    h = h_ref[...].astype(jnp.float32)        # (1, Dt)
-    w = w_ref[...].astype(jnp.float32)        # (Dt, 1) int8 codes
-    out_ref[...] += (jnp.dot(h, w, preferred_element_type=jnp.float32)
-                     * s_ref[0, 0])
-
-
-def _kernel_q4(ids_ref, hlo_ref, hhi_ref, w_ref, s_ref, out_ref):
-    d = pl.program_id(2)
-
-    @pl.when(d == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    h_lo = hlo_ref[...].astype(jnp.float32)   # (1, Dt) rows [0, D/2)
-    h_hi = hhi_ref[...].astype(jnp.float32)   # (1, Dt) rows [D/2, D)
-    p = w_ref[...].astype(jnp.int32)          # (Dt, 1) packed bytes
-    lo = ((p << 28) >> 28).astype(jnp.float32)
-    hi = (p >> 4).astype(jnp.float32)
-    part = (jnp.dot(h_lo, lo, preferred_element_type=jnp.float32)
-            + jnp.dot(h_hi, hi, preferred_element_type=jnp.float32))
-    out_ref[...] += part * s_ref[0, 0]
-
-
-def spec_head_logits_q(hn: jnp.ndarray, qt, spec_ids: jnp.ndarray,
-                       block_d: int = 512) -> jnp.ndarray:
-    """Quantized-head sibling of ``spec_head_logits``. qt: QTensor of
-    logical shape (D, V) (int8 codes or the plane-packed int4 layout from
-    ``repro.quant``). The scalar-prefetched gather streams k integer
-    columns + k scale scalars per row; dequant is the per-tile
-    scale multiply, so the result matches the dequantized reference
-    exactly (per-column scales: dequant∘gather ≡ gather∘dequant).
-    """
-    B, D = hn.shape
-    k = spec_ids.shape[1]
-    q = qt.q
-    V = q.shape[-1]
-    scale = qt.scale.reshape(1, V)
-    rows = q.shape[0]                          # D (int8) or D/2 (int4)
-    block_d = min(block_d, rows)
-    while rows % block_d:
-        block_d //= 2
-    nd = rows // block_d
-
-    w_spec = pl.BlockSpec((block_d, 1), lambda b, j, d, ids: (d, ids[b, j]))
-    s_spec = pl.BlockSpec((1, 1), lambda b, j, d, ids: (0, ids[b, j]))
-    if qt.bits == 4:
-        in_specs = [
-            pl.BlockSpec((1, block_d), lambda b, j, d, ids: (b, d)),
-            pl.BlockSpec((1, block_d),
-                         lambda b, j, d, ids, nd=nd: (b, d + nd)),
-            w_spec, s_spec,
-        ]
-        operands = (spec_ids, hn, hn, q, scale)
-        kernel = _kernel_q4
-    else:
-        in_specs = [pl.BlockSpec((1, block_d), lambda b, j, d, ids: (b, d)),
-                    w_spec, s_spec]
-        operands = (spec_ids, hn, q, scale)
-        kernel = _kernel_q8
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, k, nd),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1), lambda b, j, d, ids: (b, j)),
-    )
-    from repro.kernels import interpret_default, tpu_compiler_params
-    fn = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, k), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret_default(),
-        name=f"specee_spec_head_q{qt.bits}",
-    )
-    return fn(*operands)
+    return fn(hn, strip)

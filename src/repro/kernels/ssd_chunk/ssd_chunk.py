@@ -43,7 +43,7 @@ def ssd_chunk_fwd(xdt: jnp.ndarray, cum: jnp.ndarray, Bc: jnp.ndarray,
     xt = jnp.moveaxis(xdt, 2, 1)                       # (B, nh, c, hd)
     cumt = jnp.moveaxis(cum, 2, 1)[..., None]          # (B, nh, c, 1)
 
-    from repro.kernels import interpret_default, tpu_compiler_params
+    from repro.kernels import interpret_default
     fn = pl.pallas_call(
         functools.partial(_kernel, chunk=c),
         grid=(B, nh),
@@ -55,7 +55,7 @@ def ssd_chunk_fwd(xdt: jnp.ndarray, cum: jnp.ndarray, Bc: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, 1, c, hd), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, nh, c, hd), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_default(),
         name="ssd_chunk_diag",

@@ -9,8 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-from repro.sharding.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +21,8 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, found {len(devices)} — run "
             f"under XLA_FLAGS=--xla_force_host_platform_device_count={n}")
-    return make_mesh(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -33,7 +33,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
             f"mesh ({data},{model}) needs {data * model} devices, found "
             f"{len(devices)} — run under XLA_FLAGS="
             f"--xla_force_host_platform_device_count={data * model}")
-    return make_mesh((data, model), ("data", "model"), devices=devices)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2, devices=devices)
 
 
 def make_replica_meshes(replicas: int, model: int = 1):
@@ -51,6 +52,7 @@ def make_replica_meshes(replicas: int, model: int = 1):
             f"{replicas} replicas × model={model} need {need} devices, "
             f"found {len(devices)} — run under XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need}")
-    return [make_mesh((1, model), ("data", "model"),
-                      devices=devices[i * model:(i + 1) * model])
+    return [jax.make_mesh((1, model), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2,
+                          devices=devices[i * model:(i + 1) * model])
             for i in range(replicas)]

@@ -4,7 +4,11 @@ decode API (``repro.api``) with SpecEE as the default fast path.
 Smoke usage (CPU):
     PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
         --requests 8
-    PYTHONPATH=src python -m repro.launch.serve --mode tree --requests 4
+    PYTHONPATH=src python -m repro.launch.serve --smoke --mode tree \
+        --requests 4
+
+Without --smoke (or --ci, which implies it) the launcher serves the
+registry config at its published widths and ``ServeConfig``.
 
 Fault tolerance (DESIGN.md §7):
     --checkpoint-dir D   arm SIGTERM preemption: the engine drains, saves a
@@ -47,7 +51,9 @@ def main() -> None:
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-7b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family preset "
+                         "(RunConfig.smoke) instead of the published config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--mode", default="specee",
@@ -147,6 +153,7 @@ def main() -> None:
                 f"{need_devices}").strip()
     mode = "dense" if args.no_specee else args.mode
     if args.ci:
+        args.smoke = True
         args.requests = min(args.requests, 4)
         args.max_new = min(args.max_new, 6)
     if args.restore and not args.checkpoint_dir:
@@ -176,6 +183,9 @@ def main() -> None:
         guard = PreemptionGuard()
         guard.install()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.api import CacheSpec
     from repro.configs import get_config
     from repro.core import engine as eng
@@ -189,7 +199,9 @@ def main() -> None:
         model, params, sw = b.model, b.params, b.sw
         run = b.run
     else:
-        run = get_config(args.arch).smoke()
+        run = get_config(args.arch)
+        if args.smoke:
+            run = run.smoke()
         model = build_model(run)
         params = model.init(jax.random.PRNGKey(0))
         sw = eng.init_specee(model, jax.random.PRNGKey(1))

@@ -28,18 +28,10 @@ from repro.sharding import (batch_specs, cache_specs, param_specs,
                             specee_specs, state_specs)
 
 
-def _cast_float(tree, dtype):
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(
-            x.shape, dtype if jnp.issubdtype(x.dtype, jnp.floating)
-            else x.dtype), tree)
-
-
 def params_struct(model: Model, low_precision: bool) -> Any:
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    if low_precision:
-        return _cast_float(shapes, dtype_of(model.cfg.dtype))
-    return shapes
+    dtype = model.cfg.dtype if low_precision else model.cfg.param_dtype
+    return jax.eval_shape(lambda k: model.init(k, dtype=dtype),
+                          jax.random.PRNGKey(0))
 
 
 def batch_struct(model: Model, cell: ShapeCell) -> Dict[str, Any]:
@@ -72,11 +64,8 @@ def decode_state_struct(model: Model, cell: ShapeCell) -> eng.DecodeState:
 
 
 def specee_struct(model: Model) -> eng.SpecEEWeights:
-    sw = jax.eval_shape(lambda: eng.init_specee(model, jax.random.PRNGKey(0)))
-    # draft runs in the serving dtype
-    return eng.SpecEEWeights(
-        draft=_cast_float(sw.draft, dtype_of(model.cfg.dtype)),
-        predictors=sw.predictors, offline_mask=sw.offline_mask)
+    return jax.eval_shape(lambda: eng.init_specee(model,
+                                                 jax.random.PRNGKey(0)))
 
 
 def input_specs(model: Model, cell: ShapeCell, mesh) -> Tuple[Tuple, Tuple]:

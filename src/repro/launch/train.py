@@ -61,7 +61,8 @@ def main() -> None:
                        act_batch_axes="data" if n_dev > 1 else None,
                        act_batch_extent=mesh_shape[0])
     model = build_model(run, flags)
-    params = model.init(jax.random.PRNGKey(run.train.seed))
+    params = model.init(jax.random.PRNGKey(run.train.seed),
+                        dtype=run.model.param_dtype)
 
     loop = TrainLoop(model, run, params, ckpt_dir=args.ckpt,
                      host_id=args.host_id)

@@ -265,7 +265,7 @@ def _block_seq(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
 def _block_step(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
                 cache_entry: Any, pos: jnp.ndarray, flags: ModelFlags,
                 live_mask: Optional[jnp.ndarray] = None,
-                pages: Optional[jnp.ndarray] = None
+                pages: Optional[jnp.ndarray] = None, shard=None
                 ) -> Tuple[jnp.ndarray, Any]:
     """h: (B, D) one token; cache_entry: this block's slice of the cache.
     pos: scalar int32 — index of the current token. Returns (h_out, new_entry).
@@ -278,7 +278,11 @@ def _block_step(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
     are page pools ``(n_pages, page_size, ...)`` and every read/write goes
     through the table (``repro.core.paged``); the gathered logical view keeps
     the math bit-identical to the dense layout. Recurrent/SSD entries are
-    never paged."""
+    never paged.
+
+    shard: optional ShardCtx — the paged attention kernel then runs per
+    KV-head shard of the pools (a Pallas kernel cannot be GSPMD-partitioned).
+    """
     B, D = h.shape
     if kind in (ATTN, LOCAL_ATTN):
         x = common.apply_norm(cfg, p["ln1"], h)[:, None, :]       # (B,1,D)
@@ -328,7 +332,8 @@ def _block_step(cfg: ModelConfig, kind: str, p: Params, h: jnp.ndarray,
                 cfg, q, new_entry["k"], new_entry["v"], pages, pvec + 1,
                 window=_window(cfg, kind),
                 k_scale=new_entry["ks"] if flags.kv_quant else None,
-                v_scale=new_entry["vs"] if flags.kv_quant else None)
+                v_scale=new_entry["vs"] if flags.kv_quant else None,
+                shard=shard)
         elif flags.decode_kernel:
             from repro.kernels.decode_attention import ops as da_ops
             o = da_ops.decode_attention(cfg, q, k_cache, v_cache, pvec + 1,
@@ -539,7 +544,18 @@ class Model:
                 self.exit_point_layers.append(abs_layer - 1)
 
     # ----- init -----
-    def init(self, key) -> Params:
+    def init(self, key, dtype: Optional[str] = None) -> Params:
+        """Seeded random parameters with floating leaves in ``dtype``
+        (default ``cfg.dtype``, the serving dtype; training passes
+        ``cfg.param_dtype`` for fp32 master weights). One jit draws and
+        casts every leaf, so an fp32 copy of the whole model is never
+        resident on the device (``repro.sharding.serving.init_params``
+        draws them straight into a mesh's serving layout)."""
+        dt = common.dtype_of(dtype or self.cfg.dtype)
+        return jax.jit(
+            lambda k: common.cast_tree(self._draw(k), dt))(key)
+
+    def _draw(self, key) -> Params:
         cfg = self.cfg
         kg = KeyGen(key)
         params: Params = {"embed": common.init_embedding(cfg, kg)}
@@ -562,9 +578,6 @@ class Model:
                 "w": common.normal_init(kg(), (cfg.d_model, cfg.vocab_size),
                                         1.0 / math.sqrt(cfg.d_model))}
         return params
-
-    def param_dtype_cast(self, params: Params, dtype) -> Params:
-        return common.cast_tree(params, dtype)
 
     # ----- embedding / head -----
     def embed(self, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
@@ -824,11 +837,12 @@ class Model:
     def run_unit(self, params: Params, seg: int, unit_idx: jnp.ndarray,
                  h: jnp.ndarray, seg_cache: Any, pos: jnp.ndarray,
                  live_mask: Optional[jnp.ndarray] = None,
-                 pages: Optional[jnp.ndarray] = None
+                 pages: Optional[jnp.ndarray] = None, shard=None
                  ) -> Tuple[jnp.ndarray, Any]:
         """Run unit ``unit_idx`` (dynamic) of segment ``seg`` (static) on one
         token. h: (B, D). seg_cache: the stacked cache of this segment.
         ``pages``: the session page table when the cache is paged.
+        ``shard``: optional ShardCtx of a tensor-parallel mesh.
         Returns (h_out, updated seg_cache)."""
         unit, reps = self.segments[seg]
         up = jax.tree_util.tree_map(
@@ -840,7 +854,8 @@ class Model:
         new_entries = {}
         for i, kind in enumerate(unit):
             h, ne = _block_step(self.cfg, kind, up[f"u{i}"], h, ce[f"u{i}"],
-                                pos, self.flags, live_mask, pages=pages)
+                                pos, self.flags, live_mask, pages=pages,
+                                shard=shard)
             new_entries[f"u{i}"] = ne
         seg_cache = jax.tree_util.tree_map(
             lambda full, new: jax.lax.dynamic_update_index_in_dim(
@@ -993,12 +1008,14 @@ class Model:
         return self.logits(params, h), cache
 
     def decode_step_hidden(self, params: Params, token: jnp.ndarray,
-                           cache: Any) -> Tuple[jnp.ndarray, Any]:
+                           cache: Any, shard=None
+                           ) -> Tuple[jnp.ndarray, Any]:
         """Full-depth decode returning the PRE-final-norm hidden instead of
         logits — the emit (LM head) is the caller's: ``dense_decode_step``
         streams it through ``verify_argmax`` so greedy dense decode never
         materializes the (B, V) logits either.
-        token: (B,) int32. Returns (h (B, D), new cache)."""
+        token: (B,) int32; shard: optional ShardCtx of a tensor-parallel
+        mesh. Returns (h (B, D), new cache)."""
         h = self.embed(params, token[:, None])[:, 0, :]          # (B, D)
         pos = cache["len"]
         pages = cache.get("page_table")
@@ -1006,35 +1023,22 @@ class Model:
         for seg in range(len(self.segments)):
             seg_cache = cache["segments"][seg]
             reps = self.segments[seg][1]
-
-            def body(carry, xs):
-                h_c = carry
-                unit_params, entry = xs
-                new_entry = {}
-                hc = h_c
-                for i, kind in enumerate(self.segments[seg][0]):
-                    hc, ne = _block_step(self.cfg, kind, unit_params[f"u{i}"],
-                                         hc, entry[f"u{i}"], pos, self.flags,
-                                         pages=pages)
-                    new_entry[f"u{i}"] = jax.tree_util.tree_map(
-                        lambda n, o: n.astype(o.dtype), ne, entry[f"u{i}"])
-                return _wsc(hc, self.flags), new_entry
-
+            # the stacked cache rides in the loop carry and each unit writes
+            # its entry back in place (``run_unit``) — a scan emitting the
+            # entries as outputs would build a second copy of the cache
             if self.flags.unroll:
-                reps_n = self.segments[seg][1]
-                outs = []
-                for r in range(reps_n):
-                    up = jax.tree_util.tree_map(lambda x: x[r],
-                                                params["segments"][seg])
-                    ce = jax.tree_util.tree_map(lambda x: x[r], seg_cache)
-                    h, ne = body(h, (up, ce))
-                    outs.append(ne)
-                new_seg_cache = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *outs)
+                for u in range(reps):
+                    h, seg_cache = self.run_unit(params, seg, jnp.int32(u), h,
+                                                 seg_cache, pos, pages=pages,
+                                                 shard=shard)
             else:
-                h, new_seg_cache = jax.lax.scan(
-                    body, h, (params["segments"][seg], seg_cache))
-            new_segs.append(new_seg_cache)
+                h, seg_cache = jax.lax.fori_loop(
+                    0, reps,
+                    lambda u, c, seg=seg: self.run_unit(
+                        params, seg, u, c[0], c[1], pos, pages=pages,
+                        shard=shard),
+                    (h, seg_cache))
+            new_segs.append(seg_cache)
         return h, dict(cache, segments=new_segs, len=pos + 1)
 
 

@@ -65,11 +65,7 @@ def collective_matmul_ag(x_shard: jnp.ndarray, w: jnp.ndarray,
     for this TP group, rows ordered by source rank.
     Called inside shard_map with ``axis_name`` a mesh axis of size P.
     """
-    # jax<=0.4.x has no jax.lax.axis_size; psum(1) is the portable spelling
-    if hasattr(jax.lax, "axis_size"):
-        P_ = jax.lax.axis_size(axis_name)
-    else:
-        P_ = int(jax.lax.psum(1, axis_name))
+    P_ = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     rows = x_shard.shape[0]
 
@@ -88,9 +84,7 @@ def collective_matmul_ag(x_shard: jnp.ndarray, w: jnp.ndarray,
 
     out0 = jnp.zeros((rows * P_, w.shape[1]), x_shard.dtype)
     # mark the accumulator as device-varying along the ring axis (shard_map
-    # VMA typing: the carry is written with per-device data every hop);
-    # jax<=0.4.x has no VMA typing and no pvary — the constant carry is fine
-    if hasattr(jax.lax, "pvary"):
-        out0 = jax.lax.pvary(out0, (axis_name,))
+    # VMA typing: the carry is written with per-device data every hop)
+    out0 = jax.lax.pcast(out0, (axis_name,), to="varying")
     buf, out = jax.lax.fori_loop(0, P_, step, (x_shard, out0))
     return out

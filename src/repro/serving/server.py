@@ -618,12 +618,11 @@ class ServingEngine:
                 "device_lost",
                 f"device lost with no valid remesh (tp={self.tp_degree}, "
                 f"surviving={len(surviving)})")
-        if new_tp > 1:
-            from repro.sharding.compat import make_mesh
-            new_mesh = make_mesh((1, new_tp), ("data", "model"),
-                                 devices=surviving[:new_tp])
-        else:
-            new_mesh = None
+        # a (1, 1) mesh at TP 1 keeps the engine on its surviving device
+        new_mesh = jax.make_mesh(
+            (1, new_tp), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+            devices=surviving[:new_tp])
         self.remesh(new_mesh, detail=f"lost={lost}")
 
     def cancel(self, uid: int) -> bool:

@@ -1,5 +1,3 @@
-from repro.sharding.compat import (axis_types_kwargs, make_mesh, shard_map,
-                                   shard_map_unchecked)
 from repro.sharding.ctx import ShardCtx
 from repro.sharding.policies import (batch_specs, cache_specs, named,
                                      param_specs, specee_specs, state_specs)

@@ -71,3 +71,13 @@ def engine_shardings(model, mesh: Mesh, policy: str, params, sw, qw
                if sw is not None else None)
     q_named = (pol.named(mesh, _replicated(qw)) if qw is not None else None)
     return p_named, s_named, q_named
+
+
+def init_params(model, key, mesh: Mesh) -> Any:
+    """``model.init(key)`` drawn straight into the layout ``Engine`` gives
+    the parameters on ``mesh`` (default policy), for a model that fits the
+    mesh but not one of its devices. ``Model.init`` is one jit, which this
+    outer jit inlines, so no unsharded or fp32 copy is ever resident."""
+    shapes = jax.eval_shape(model.init, key)
+    p_named, _, _ = engine_shardings(model, mesh, "tp_dp", shapes, None, None)
+    return jax.jit(model.init, out_shardings=p_named)(key)

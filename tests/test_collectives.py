@@ -32,18 +32,18 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime import collectives as coll
-from repro.sharding import compat
+from jax.sharding import AxisType
 
-mesh = compat.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
 for rows, K, N in [(8, 16, 12), (4, 32, 32)]:
     x = jax.random.normal(jax.random.PRNGKey(0), (rows * 4, K), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)
     # x arrives row-sharded; w replicated; out replicated on every device
     # (each device assembles the full (rows*4, N) product via the ring, so
-    # there is no replication certificate — check_rep must be off)
-    fn = compat.shard_map_unchecked(
+    # there is no replication certificate — check_vma must be off)
+    fn = jax.shard_map(
         lambda xs, ws: coll.collective_matmul_ag(xs, ws, "model"),
-        mesh, in_specs=(P("model", None), P(None, None)),
+        mesh=mesh, check_vma=False, in_specs=(P("model", None), P(None, None)),
         out_specs=P(None, None))
     got = np.asarray(fn(x, w))
     want = np.asarray(jnp.dot(x, w, preferred_element_type=jnp.float32))
@@ -68,13 +68,13 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime import collectives as coll
-from repro.sharding import compat
+from jax.sharding import AxisType
 
-mesh = compat.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
 DEG, T, D = 4, 24, 64
-fn = compat.shard_map(
+fn = jax.shard_map(
     lambda xs, es: coll.compressed_psum(xs, "model", es),
-    mesh, in_specs=(P("model", None), P("model", None)),
+    mesh=mesh, in_specs=(P("model", None), P("model", None)),
     out_specs=(P(None, None), P("model", None)))
 
 key = jax.random.PRNGKey(3)

@@ -26,10 +26,12 @@ def _inputs(B, D, V, k, dtype=jnp.float32, seed=0):
 
 
 # ---------------- gate kernel vs oracle ----------------
-# shapes cover: 128-aligned, non-128-aligned D AND V, k≠4, and the tree
-# path's B·N row layout (B=2 × N=13 nodes)
+# shapes cover: 128-aligned, non-128-aligned D AND V, k≠4, the tree
+# path's B·N row layout (B=2 × N=13 nodes), and the serving batches 8 and
+# 16 whose rows the kernels block together
 GATE_SHAPES = [(4, 256, 512, 4), (3, 384, 1001, 4), (2, 320, 777, 5),
-               (26, 128, 512, 4), (1, 200, 65, 3)]
+               (26, 128, 512, 4), (1, 200, 65, 3), (8, 512, 1000, 4),
+               (16, 256, 640, 4)]
 
 
 @pytest.mark.parametrize("B,D,V,k", GATE_SHAPES)
@@ -67,7 +69,8 @@ def test_exit_gate_non_2layer_bank_falls_back():
 
 # ---------------- streaming argmax-verify vs oracle ----------------
 VERIFY_SHAPES = [(4, 256, 512), (3, 384, 1001), (2, 320, 777),
-                 (26, 128, 512), (1, 200, 1300)]
+                 (26, 128, 512), (1, 200, 1300), (8, 512, 1024),
+                 (16, 384, 777)]
 
 
 @pytest.mark.parametrize("B,D,V", VERIFY_SHAPES)
@@ -99,7 +102,8 @@ def test_verify_argmax_tie_breaks_to_first():
 
 # ---------------- streaming top-k verify vs oracle ----------------
 TOPK_SHAPES = [(4, 256, 512, 4), (3, 384, 1001, 4), (2, 320, 777, 5),
-               (1, 200, 65, 3), (26, 128, 512, 4)]
+               (1, 200, 65, 3), (26, 128, 512, 4), (8, 512, 1024, 4),
+               (16, 256, 777, 4)]
 
 
 @pytest.mark.parametrize("B,D,V,k", TOPK_SHAPES)

@@ -181,6 +181,33 @@ def test_spec_head_quantized_parity(bits):
 
 
 @pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("B", [8, 16])
+def test_quantized_kernels_at_serving_batch(B, bits):
+    """The quantized verify, top-k and spec-head kernels block all B rows
+    together; at the serving batches 8 and 16 they match the dequantized
+    oracle like the small-B cases above."""
+    hn = jax.random.normal(jax.random.PRNGKey(15), (B, 256))
+    w = jax.random.normal(jax.random.PRNGKey(16), (256, 640)) * 0.1
+    ids = jax.random.randint(jax.random.PRNGKey(17), (B, 4), 0, 640)
+    qt = quant.quantize_tensor(w, bits)
+    wd = qt.dequantize()
+    tok, val = gate_ops.verify_argmax(hn, qt, impl="kernel", block_v=128)
+    ref_tok, ref_val = gate_ref.verify_argmax_ref(hn, wd)
+    np.testing.assert_array_equal(np.asarray(tok), np.asarray(ref_tok))
+    np.testing.assert_allclose(np.asarray(val), np.asarray(ref_val),
+                               rtol=1e-5, atol=1e-5)
+    tids, tvals = gate_ops.verify_topk(hn, qt, 4, impl="kernel", block_v=128)
+    ref_ids, ref_vals = gate_ref.verify_topk_ref(hn, wd, 4)
+    np.testing.assert_array_equal(np.asarray(tids), np.asarray(ref_ids))
+    np.testing.assert_allclose(np.asarray(tvals), np.asarray(ref_vals),
+                               rtol=1e-5, atol=1e-5)
+    logits, _ = sh_ops.spec_head(hn, qt, ids)
+    ref_logits, _ = sh_ops.spec_head(hn, wd, ids)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
 def test_predictor_mlp_quantized_parity(bits):
     x = jax.random.normal(jax.random.PRNGKey(12), (4, 9))
     p = {"layers": [

@@ -376,7 +376,7 @@ from repro.models.model import build_model
 from repro.runtime import faultinject
 from repro.runtime.faultinject import FaultSchedule
 from repro.serving import ServingEngine
-from repro.sharding.compat import make_mesh
+from jax.sharding import AxisType
 
 run = get_config("llama2-7b").smoke()
 run = dataclasses.replace(
@@ -411,8 +411,9 @@ def stats(se):
 for cache in ("dense", "paged"):
     ref = serve(cache)              # fault-free UNSHARDED reference
     for kill_tick in (1, 2, 3):
-        mesh = make_mesh((1, 2), ("data", "model"),
-                         devices=jax.devices()[:2])
+        mesh = jax.make_mesh((1, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2,
+                             devices=jax.devices()[:2])
         with faultinject.injected(
                 FaultSchedule.once("device_lost", visit=kill_tick)) as inj:
             se = serve(cache, mesh=mesh)

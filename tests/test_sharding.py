@@ -15,8 +15,9 @@ from repro.models.model import build_model
 
 
 def _mesh11():
-    from repro.sharding.compat import make_mesh
-    return make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 @pytest.mark.parametrize("arch,policy", [
@@ -73,9 +74,10 @@ from repro.launch.specs import input_specs
 from repro.launch.dryrun import step_fn_for
 from repro.config import ShapeCell
 
-from repro.sharding.compat import make_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 run = get_config("llama2-7b").smoke()
 for cell, kind in [(ShapeCell("train_4k", "train", 32, 4), "train"),
                    (ShapeCell("decode_32k", "decode", 64, 4), "decode")]:
@@ -112,14 +114,15 @@ _SHARDED_VERIFY = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from repro.sharding.compat import make_mesh
+from jax.sharding import AxisType
 from repro.sharding.ctx import ShardCtx
 from repro.kernels.exit_gate import ops as gate_lib
 
 key = jax.random.PRNGKey(0)
 for degree in (2, 4):
-    mesh = make_mesh((1, degree), ("data", "model"),
-                     devices=jax.devices()[:degree])
+    mesh = jax.make_mesh((1, degree), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:degree])
     shard = ShardCtx.from_mesh(mesh)
     for V in (512, 509, 500):       # divisible / odd / pad-needed vocabs
         for impl in ("ref", "xla", "kernel"):
@@ -144,7 +147,9 @@ for degree in (2, 4):
 # contract) for argmax and lower-index-first ordering for top-k
 hn = jnp.ones((2, 8), jnp.float32)
 w = jnp.tile(jax.random.normal(key, (8, 16), jnp.float32), (1, 2))
-mesh = make_mesh((1, 2), ("data", "model"), devices=jax.devices()[:2])
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2,
+                     devices=jax.devices()[:2])
 shard = ShardCtx.from_mesh(mesh)
 t0, _ = gate_lib.verify_argmax(hn, w, impl="ref")
 t1, _ = gate_lib.verify_argmax(hn, w, impl="ref", shard=shard)
@@ -187,7 +192,7 @@ from repro.api import Engine
 from repro.configs import get_config
 from repro.core import engine as eng
 from repro.models.model import build_model
-from repro.sharding import compat
+from jax.sharding import AxisType
 
 DEGREES = %s
 
@@ -219,8 +224,9 @@ def decode(run, m, params, sw, strategy, cache, mesh, K=2):
 
 run, m, params, sw = build()
 for deg in DEGREES:
-    mesh = compat.make_mesh((1, deg), ("data", "model"),
-                            devices=jax.devices()[:deg])
+    mesh = jax.make_mesh((1, deg), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:deg])
     for strategy in ("dense", "specee", "tree"):
         for cache in ("dense", "paged"):
             ref = decode(run, m, params, sw, strategy, cache, mesh=None)
@@ -230,8 +236,9 @@ for deg in DEGREES:
 # non-divisible vocab (509, indivisible by any degree): padded-shard verify
 # inside a real decode loop
 run, m, params, sw = build(vocab=509)
-mesh = compat.make_mesh((1, DEGREES[0]), ("data", "model"),
-                        devices=jax.devices()[:DEGREES[0]])
+mesh = jax.make_mesh((1, DEGREES[0]), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2,
+                     devices=jax.devices()[:DEGREES[0]])
 ref = decode(run, m, params, sw, "specee", "paged", mesh=None)
 got = decode(run, m, params, sw, "specee", "paged", mesh=mesh)
 assert got == ref, (got, ref)
@@ -249,6 +256,84 @@ def test_sharded_decode_token_parity(degrees):
     out = _run_subprocess(_SHARDED_DECODE % repr(tuple(degrees)))
     assert "SHARD-DECODE-OK" in out, out
     assert "ODD-VOCAB-OK" in out, out
+
+
+_KERNELS_UNDER_MESH = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+import numpy as np
+from repro.api import Engine
+from repro.configs import get_config
+from repro.core import engine as eng
+from repro.launch.mesh import make_replica_meshes
+from repro.models.model import ModelFlags, build_model
+
+run = get_config("llama2-7b").smoke()
+# every served-path kernel on: fused exit gate, verify, paged attention
+m = build_model(run, ModelFlags(exit_gate_kernel=True, exit_gate_impl="kernel",
+                                decode_kernel=True))
+params = m.init(jax.random.PRNGKey(0))
+sw = eng.init_specee(m, jax.random.PRNGKey(1))
+prompts = np.asarray(jax.random.randint(
+    jax.random.PRNGKey(7), (2, 8), 0, run.model.vocab_size))
+
+
+def decode(strategy, mesh):
+    e = Engine.create(m, params, sw, strategy=strategy, mesh=mesh)
+    s = e.new_session(batch=2, max_seq=48, cache="paged")
+    toks = [[s.prefill_row(b, prompts[b], max_new_tokens=4)]
+            for b in range(2)]
+    while not s.all_done():
+        res = s.step(num_ticks=2)
+        for b in range(2):
+            toks[b].extend(res.row_tokens(b))
+    return e, s, toks
+
+
+tp2 = jax.make_mesh((1, 2), ("data", "model"),
+                    axis_types=(jax.sharding.AxisType.Auto,) * 2)
+for strategy in ("dense", "specee", "tree"):
+    _, _, ref = decode(strategy, None)
+    _, _, got = decode(strategy, tp2)
+    assert got == ref, (strategy, got, ref)
+    print("TP-KERNELS-OK", strategy)
+# one-chip replica meshes pin each engine to its own device
+_, _, ref = decode("specee", None)
+for i, mesh in enumerate(make_replica_meshes(2, 1)):
+    e, s, toks = decode("specee", mesh)
+    state, _ = s.snapshot()
+    placed = {d for leaf in jax.tree_util.tree_leaves((e.params, e.sw, state))
+              for d in leaf.devices()}
+    assert placed == {jax.devices()[i]}, (i, placed)
+    assert toks == ref, (i, toks, ref)
+print("REPLICA-PLACEMENT-OK")
+# parameters drawn straight into a mesh's serving layout: the values of
+# model.init, in the layout Engine places them in
+from repro.sharding.serving import init_params
+drawn = init_params(m, jax.random.PRNGKey(0), tp2)
+placed = Engine.create(m, params, sw, strategy="dense", mesh=tp2).params
+leaves = jax.tree_util.tree_leaves
+for d, p, e in zip(leaves(drawn), leaves(params), leaves(placed)):
+    assert d.dtype == p.dtype and np.array_equal(d, p)
+    assert d.sharding.is_equivalent_to(e.sharding, d.ndim), (d.sharding,
+                                                             e.sharding)
+print("INIT-PARAMS-OK")
+"""
+
+
+def test_kernels_under_mesh_and_replica_placement():
+    """Under a tensor-parallel mesh every Pallas kernel of the served path
+    (fused exit gate, streaming verify, paged decode attention, tree
+    predictor) runs inside a shard_map — GSPMD cannot partition a kernel —
+    and decodes token-identically to the unsharded engine; a one-chip
+    replica mesh keeps its engine's weights and state on its own device;
+    ``init_params`` draws ``model.init``'s values in the engine's layout."""
+    out = _run_subprocess(_KERNELS_UNDER_MESH)
+    for strategy in ("dense", "specee", "tree"):
+        assert f"TP-KERNELS-OK {strategy}" in out, out
+    assert "REPLICA-PLACEMENT-OK" in out, out
+    assert "INIT-PARAMS-OK" in out, out
 
 
 def test_pool_partition_dims():

@@ -221,16 +221,16 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.runtime.collectives import collective_matmul_ag, compressed_psum
-from repro.sharding.compat import make_mesh, shard_map
-mesh = make_mesh((4,), ("tp",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("tp",), axis_types=(AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 16))
 w = jax.random.normal(jax.random.PRNGKey(1), (16, 32)) * 0.1
 
 # x row-sharded over tp; w column-sharded (Megatron column-parallel layout);
 # each device ends with full rows x its N-shard -> out_specs P(None, "tp")
-f = shard_map(lambda xs, ws: collective_matmul_ag(xs, ws, "tp"),
-              mesh=mesh, in_specs=(P("tp", None), P(None, "tp")),
-              out_specs=P(None, "tp"))
+f = jax.shard_map(lambda xs, ws: collective_matmul_ag(xs, ws, "tp"),
+                  mesh=mesh, in_specs=(P("tp", None), P(None, "tp")),
+                  out_specs=P(None, "tp"))
 got = f(x, w)
 np.testing.assert_allclose(got.astype(np.float32), (x @ w), atol=1e-4)
 
@@ -241,9 +241,9 @@ def cpsum(gs, es):
     red, new_err = compressed_psum(gs[0], "tp", es[0])
     return red, new_err[None]
 
-f2 = shard_map(cpsum, mesh=mesh,
-               in_specs=(P("tp", None), P("tp", None)),
-               out_specs=(P(None), P("tp", None)))
+f2 = jax.shard_map(cpsum, mesh=mesh,
+                   in_specs=(P("tp", None), P("tp", None)),
+                   out_specs=(P(None), P("tp", None)))
 red, err = f2(g, err0)
 rel = float(jnp.linalg.norm(red - g.sum(0)) / jnp.linalg.norm(g.sum(0)))
 assert rel < 0.05, rel

@@ -320,7 +320,7 @@ _GATE_ABS_SLACK_US = 250.0      # absolute noise floor added to every ceiling
 
 def gate(threshold: float = 0.5) -> int:
     """CI perf gate over the committed ``BENCH_exit_gate.json`` row groups
-    (ROADMAP item 5, the exit-gate counterpart of ``bench_serving --gate``):
+    (ROADMAP item 5):
 
       * ``gate_ab``       — re-measure the fused gate per committed shape and
                             fail when fresh > (1 + threshold) × committed

@@ -105,14 +105,6 @@ if [ "$#" -eq 0 ]; then
   PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}" \
     python -m repro.launch.serve --ci --replicas 2
 
-  # serving perf gate (ROADMAP item 5): re-measure the core serving
-  # variants and fail on a >20% decode_tok_s regression vs the committed
-  # BENCH_serving.json rows (skips gracefully when rows are missing or
-  # recorded on a different backend).
-  echo "[ci] bench_serving --gate (decode_tok_s regression gate)"
-  PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
-    python -m benchmarks.bench_serving --gate
-
   # exit-gate perf gate (ROADMAP item 5): re-measure the fused gate and the
   # quantized streaming verify against the committed BENCH_exit_gate.json
   # row groups; quant_pareto quality (match_vs_dense_fp32 == 1.0) is

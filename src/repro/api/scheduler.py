@@ -59,6 +59,7 @@ class ChunkedPrefillScheduler:
         self.queue: Deque[_Pending] = deque()
         self._active: Optional[Tuple[int, Admission]] = None
         self.last_tick_tokens = 0       # prefill tokens run by the last tick
+        self.last_tick_chunks = 0       # prefill chunks run by the last tick
         # consecutive ticks the queue head sat blocked on ``can_admit`` while
         # a free slot was available — the serving engine's pool-pressure
         # signal (``>= evict_patience`` triggers victim eviction, DESIGN §7)
@@ -101,7 +102,7 @@ class ChunkedPrefillScheduler:
         """
         events: List[Admitted] = []
         free = [r for r in free_rows if r not in self.busy_rows()]
-        self.last_tick_tokens = 0
+        self.last_tick_tokens = self.last_tick_chunks = 0
         deferred = False
         while True:
             if self._active is None:
@@ -120,6 +121,7 @@ class ChunkedPrefillScheduler:
             uid, adm = self._active
             n = self.session.prefill_chunk(adm, self.chunk_tokens)
             self.last_tick_tokens += n
+            self.last_tick_chunks += 1
             if adm.complete:
                 events.append(Admitted(uid=uid, row=adm.row,
                                        first_token=adm.first_token))

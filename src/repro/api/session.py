@@ -55,6 +55,7 @@ from repro.core import draft as draft_lib
 from repro.core import scheduler as sched_lib
 from repro.models.model import Model
 from repro.runtime import faultinject
+from repro.runtime.telemetry import spanned
 
 from repro.api.cache import (CacheSpec, KVCacheManager, insert_row_pytree,
                              make_cache_manager)
@@ -128,14 +129,17 @@ class Engine:
         shard = self.shard
         # the decode state (KV cache pytree included — paged pools + page
         # table too) is DONATED: XLA updates the cache in place every tick
-        # instead of reallocating it, and stale state references fail loudly
-        self._step_jit = jax.jit(
-            lambda p, s, st, qw: strat.step(model, p, s, st, qw=qw,
-                                            shard=shard),
-            donate_argnums=(2,))
-        self._extend_jit = jax.jit(
-            lambda p, toks, cache, n: model.prefill_extend(p, toks, cache, n),
-            donate_argnums=(2,))
+        # instead of reallocating it, and stale state references fail loudly.
+        # Named functions name the programs (``jit_decode_step`` ...) in a
+        # profiler trace.
+        def decode_step(p, s, st, qw):
+            return strat.step(model, p, s, st, qw=qw, shard=shard)
+
+        def prefill_extend(p, toks, cache, n):
+            return model.prefill_extend(p, toks, cache, n)
+
+        self._step_jit = jax.jit(decode_step, donate_argnums=(2,))
+        self._extend_jit = jax.jit(prefill_extend, donate_argnums=(2,))
         self._mega_jits = {}
 
     def megatick_jit(self, num_ticks: int):
@@ -148,10 +152,11 @@ class Engine:
         fn = self._mega_jits.get(num_ticks)
         if fn is None:
             strat, model, shard = self.strategy, self.model, self.shard
-            fn = jax.jit(
-                lambda p, s, st, limits, qw: strat.megatick(
-                    model, p, s, st, limits, num_ticks, qw=qw, shard=shard),
-                donate_argnums=(2,))
+
+            def decode_megatick(p, s, st, limits, qw):
+                return strat.megatick(model, p, s, st, limits, num_ticks,
+                                      qw=qw, shard=shard)
+            fn = jax.jit(decode_megatick, donate_argnums=(2,))
             self._mega_jits[num_ticks] = fn
         return fn
 
@@ -402,7 +407,8 @@ class DecodeSession:
                           exit_layer=np.asarray(raw.exit_layer),
                           accept_len=np.asarray(raw.accept_len),
                           exited=np.asarray(raw.exited),
-                          units_run=np.asarray(raw.units_run))
+                          units_run=np.asarray(raw.units_run),
+                          head_passes=np.asarray(raw.head_passes))
 
     def all_done(self) -> bool:
         return self._state is None or bool(self._done.all())
@@ -566,7 +572,8 @@ class DecodeSession:
             exit_layer=jnp.full((B,), E, jnp.int32),
             accept_len=jnp.zeros((B,), jnp.int32),
             exited=jnp.zeros((B,), bool),
-            units_run=jnp.int32(0))
+            units_run=jnp.int32(0),
+            head_passes=jnp.int32(0))
         return self._wrap(raw)
 
     # ----- slot-based admission (continuous batching) -----
@@ -628,6 +635,7 @@ class DecodeSession:
         return Admission(row=row, tokens=np.asarray(prompt, np.int64),
                          max_new_tokens=max_new_tokens, eos_token=eos_token)
 
+    @spanned("serve.admit.chunk")
     def prefill_chunk(self, adm: Admission,
                       max_tokens: Optional[int] = None) -> int:
         """Run at most ``max_tokens`` prompt tokens of ``adm``'s prefill.
@@ -668,6 +676,7 @@ class DecodeSession:
             self._finish_admission(adm)
         return n
 
+    @spanned("serve.admit.finish")
     def _finish_admission(self, adm: Admission) -> None:
         """Last chunk done: first token, draft prefill over the accumulated
         hiddens, batch-1 state assembly, row insert."""
@@ -786,6 +795,7 @@ class DecodeSession:
             accept_len=np.asarray(out["accept_len"]),
             exited=np.asarray(out["exited"]),
             units_run=np.asarray(out["units_run"]),
+            head_passes=np.asarray(out["head_passes"]),
             ticks=int(np.asarray(out["ticks"])),
             tick_counts=np.asarray(out["tick_counts"]),
             tick_live=np.asarray(out["tick_live"]))

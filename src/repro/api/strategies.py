@@ -37,7 +37,8 @@ def _single_token_result(token, info: eng.StepInfo) -> StepResult:
                       exit_layer=info.exit_point,
                       accept_len=jnp.zeros((B,), jnp.int32),
                       exited=info.exited,
-                      units_run=info.units_run)
+                      units_run=info.units_run,
+                      head_passes=info.head_passes)
 
 
 @dataclass(frozen=True)
@@ -104,7 +105,8 @@ class DecodeStrategy:
                                 exit_layer=res.exit_layer,
                                 accept_len=res.accept_len,
                                 exited=res.exited,
-                                units_run=res.units_run), new_st
+                                units_run=res.units_run,
+                                head_passes=res.head_passes), new_st
         return eng.megatick_decode(tick, state, limits, num_ticks,
                                    self.emit_width(model),
                                    model.num_exit_points)
@@ -194,7 +196,8 @@ class TreeStrategy(DecodeStrategy):
                          exit_layer=info.exit_point,
                          accept_len=info.accepted_len,
                          exited=info.exited,
-                         units_run=info.units_run)
+                         units_run=info.units_run,
+                         head_passes=info.head_passes)
         return res, new_state
 
 

@@ -45,6 +45,8 @@ class StepResult(NamedTuple):
     #                     (megatick: (B, K))
     units_run: Any     # ()     int32 — units the layer loop executed
     #                     (megatick: summed over the ticks that ran)
+    head_passes: Any   # ()     int32 — full LM-head passes: the draft's
+    #                     top-k, each verify, the final emit (megatick: summed)
     ticks: Any = 1     # ()     int   — device ticks folded into this result
     tick_counts: Any = None   # (B, K) int32 — kept tokens per tick
     #                     (megatick only; None for single-tick results)

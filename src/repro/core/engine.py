@@ -117,7 +117,7 @@ class StepInfo(NamedTuple):
     exit_point: jnp.ndarray     # (B,) unit index at exit (E if ran full depth)
     exited: jnp.ndarray         # (B,) bool — predictor-driven exit happened
     units_run: jnp.ndarray      # () int32 — units the while loops executed
-    spec_hit: jnp.ndarray       # (B,) bool — final token ∈ speculative set
+    head_passes: jnp.ndarray    # () int32 — full LM-head passes the step ran
 
 
 def init_specee(model: Model, key) -> SpecEEWeights:
@@ -223,10 +223,12 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
     emb = model.embed(params, state.last_token[:, None])[:, 0, :]
     h_draft, draft_cache = draft_lib.draft_step(
         model.cfg, sw.draft, emb, state.h_last, state.draft_cache, pos)
-    spec_ids, _ = draft_lib.propose_topk(model, params, h_draft, k,
-                                         lm_w=lm_w, shard=shard)
-    if spec_ids_override is not None:
-        spec_ids = spec_ids_override
+    if spec_ids_override is None:
+        spec_ids, _ = draft_lib.propose_topk(model, params, h_draft, k,
+                                             lm_w=lm_w, shard=shard)
+        head_passes = jnp.int32(1)
+    else:
+        spec_ids, head_passes = spec_ids_override, jnp.int32(0)
 
     # ---- 2. T2 scheduling: which exit points run a predictor ----
     active = sched_lib.active_mask(state.sched, sw.offline_mask, spec, E)
@@ -248,7 +250,8 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
             return (u < reps) & ~jnp.all(c[3])
 
         def body(c):
-            u, h, seg_cache, exited, exit_token, exit_pt, prev_probs, nrun = c
+            (u, h, seg_cache, exited, exit_token, exit_pt, prev_probs, nrun,
+             npass) = c
             live = ~exited
             h_new, seg_cache = model.run_unit(params, seg, u, h, seg_cache,
                                               pos, live_mask=live,
@@ -279,26 +282,28 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
                     exit_pt = jnp.where(newly, ep, exit_pt)
                     return exited | newly, exit_token, exit_pt
 
+                fire = jnp.any(would)
                 exited, exit_token, exit_pt = jax.lax.cond(
-                    jnp.any(would), verify, lambda a: a,
+                    fire, verify, lambda a: a,
                     (exited, exit_token, exit_pt))
                 prev_probs = jnp.where(act[:, None], probs, prev_probs)
-                return prev_probs, exited, exit_token, exit_pt
+                return (prev_probs, exited, exit_token, exit_pt,
+                        fire.astype(jnp.int32))
 
             def without_predictor(args):
                 h, prev_probs, exited, exit_token, exit_pt = args
-                return prev_probs, exited, exit_token, exit_pt
+                return prev_probs, exited, exit_token, exit_pt, jnp.int32(0)
 
-            prev_probs, exited, exit_token, exit_pt = jax.lax.cond(
+            prev_probs, exited, exit_token, exit_pt, verified = jax.lax.cond(
                 jnp.any(act), with_predictor, without_predictor,
                 (h, prev_probs, exited, exit_token, exit_pt))
             return (u + 1, h, seg_cache, exited, exit_token, exit_pt,
-                    prev_probs, nrun + 1)
+                    prev_probs, nrun + 1, npass + verified)
 
         carry = (jnp.int32(0), h, seg_cache, exited, exit_token, exit_pt,
-                 prev_probs, units_run)
+                 prev_probs, units_run, head_passes)
         u_end, h, seg_cache, exited, exit_token, exit_pt, prev_probs, \
-            units_run = jax.lax.while_loop(cond, body, carry)
+            units_run, head_passes = jax.lax.while_loop(cond, body, carry)
 
         # ---- 4. KV/state propagation for units the loop never reached ----
         def pcond(c):
@@ -319,7 +324,6 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
     final_tok, _ = gate_lib.verify_argmax(model.final_norm(params, h), lm_w,
                                           impl=gate_impl, shard=shard)
     token = jnp.where(exited, exit_token, final_tok)
-    spec_hit = jnp.any(token[:, None] == spec_ids, axis=1)
 
     # ---- 6. bookkeeping ----
     sched = sched_lib.update(state.sched,
@@ -333,7 +337,7 @@ def ar_decode_step(model: Model, params: Params, sw: SpecEEWeights,
         prng=state.prng,
     )
     info = StepInfo(exit_point=exit_pt, exited=exited, units_run=units_run,
-                    spec_hit=spec_hit)
+                    head_passes=head_passes + 1)        # + the final emit
     return token, new_state, info
 
 
@@ -345,6 +349,7 @@ class TreeStepInfo(NamedTuple):
     exit_point: jnp.ndarray     # (B,) unit index at exit
     exited: jnp.ndarray         # (B,)
     units_run: jnp.ndarray      # ()
+    head_passes: jnp.ndarray    # () — full LM-head passes the step ran
 
 
 def build_tree(model: Model, params: Params, sw: SpecEEWeights,
@@ -570,8 +575,10 @@ def tree_decode_step(model: Model, params: Params, sw: SpecEEWeights,
     sched = sched_lib.update(state.sched, jnp.minimum(exit_pt, E - 1))
     new_state = DecodeState(cache=cache, draft_cache=draft_cache, sched=sched,
                             last_token=bonus, h_last=h_last, prng=state.prng)
+    # one draft-logits pass per tree level, one verify over the nodes
     info = TreeStepInfo(accepted_len=acc_len - 1, exit_point=exit_pt,
-                        exited=exited, units_run=units_run)
+                        exited=exited, units_run=units_run,
+                        head_passes=jnp.int32(tree.depth + 1))
     return out_tokens, n_emit, new_state, info
 
 
@@ -586,6 +593,7 @@ class TickEmit(NamedTuple):
     accept_len: jnp.ndarray     # (B,) int32
     exited: jnp.ndarray         # (B,) bool
     units_run: jnp.ndarray      # () int32
+    head_passes: jnp.ndarray    # () int32
 
 
 def megatick_decode(tick_fn, state: DecodeState, limits: Dict[str, jnp.ndarray],
@@ -612,7 +620,8 @@ def megatick_decode(tick_fn, state: DecodeState, limits: Dict[str, jnp.ndarray],
 
     Returns ``(out, final_state, new_limits)`` where ``out`` holds tokens
     (B, K·W), counts (B,), per-tick stat planes (B, K), ``ticks`` actually
-    run, and the final done mask; ``new_limits`` is the advanced carry for
+    run, ``units_run`` and ``head_passes`` summed over them, and the final
+    done mask; ``new_limits`` is the advanced carry for
     the next megatick (device-resident across calls — no host round-trip).
     """
     K, W = int(num_ticks), int(emit_width)
@@ -666,6 +675,7 @@ def megatick_decode(tick_fn, state: DecodeState, limits: Dict[str, jnp.ndarray],
             tick_counts=c["tick_counts"].at[:, t].set(kept),
             tick_live=c["tick_live"].at[:, t].set(live),
             units=c["units"] + em.units_run,
+            passes=c["passes"] + em.head_passes,
         )
 
     init = dict(
@@ -679,13 +689,15 @@ def megatick_decode(tick_fn, state: DecodeState, limits: Dict[str, jnp.ndarray],
         tick_counts=jnp.zeros((B, K), jnp.int32),
         tick_live=jnp.zeros((B, K), bool),
         units=jnp.int32(0),
+        passes=jnp.int32(0),
     )
     fin = jax.lax.while_loop(cond, body, init)
     out = {"tokens": fin["buf"], "counts": fin["counts"],
            "exit_layer": fin["exit_layer"], "accept_len": fin["accept_len"],
            "exited": fin["exited"], "tick_counts": fin["tick_counts"],
            "tick_live": fin["tick_live"], "ticks": fin["t"],
-           "units_run": fin["units"], "done": fin["done"]}
+           "units_run": fin["units"], "head_passes": fin["passes"],
+           "done": fin["done"]}
     new_limits = {"budget": budget, "emitted": fin["emitted"], "eos": eos,
                   "done": fin["done"], "retired": retired}
     return out, fin["state"], new_limits
@@ -751,5 +763,5 @@ def dense_decode_step(model: Model, params: Params,
     info = StepInfo(exit_point=jnp.full((B,), E, jnp.int32),
                     exited=jnp.zeros((B,), bool),
                     units_run=jnp.int32(E),
-                    spec_hit=jnp.zeros((B,), bool))
+                    head_passes=jnp.int32(1))
     return token, new_state, info

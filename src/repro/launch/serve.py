@@ -190,6 +190,7 @@ def main() -> None:
     from repro.configs import get_config
     from repro.core import engine as eng
     from repro.models.model import build_model
+    from repro.runtime import telemetry
     from repro.runtime.faultinject import FaultSchedule
     from repro.serving import Preempted, ServingEngine
 
@@ -336,6 +337,8 @@ def main() -> None:
           f"megatick={args.megatick}, async={engine.async_ticks}, "
           f"fused_gate={not args.no_fused_gate}, "
           f"quant={args.quant or 'fp'})")
+    print(f"[serve] counters: {engine.counters}")
+    print("[serve] compiles by span:\n" + telemetry.compile_table())
     if inj is not None:
         assert args.inject in inj.fired_sites(), \
             f"--inject {args.inject} never fired (schedule {schedule.plan})"

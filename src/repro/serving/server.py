@@ -86,6 +86,7 @@ from repro.checkpoint import CheckpointManager
 from repro.models.model import Model, build_model
 from repro.runtime import faultinject
 from repro.runtime.fault import PreemptionGuard, plan_replica_remesh
+from repro.runtime.telemetry import Counters, span, spanned
 from repro.serving.resilience import (Backoff, FaultEvent, FaultLog,
                                       Preempted, ServingFault, VictimInfo,
                                       VictimPolicy)
@@ -226,6 +227,7 @@ class ServingEngine:
         self._tick = 0
         self.fault_log = FaultLog(cap=fault_log_cap)
         self.completed: List[Request] = []   # finish order, survives restore
+        self.counters = Counters()      # work run, for the engine's life
 
     @property
     def tp_degree(self) -> int:
@@ -334,6 +336,19 @@ class ServingEngine:
 
     def _collect(self, res, slots: List[Optional[Request]],
                  finished: List[Request]) -> None:
+        """Count one result's device work in ``counters`` (replayed ticks
+        too: they are device work) and fold it into its requests, inside a
+        ``serve.collect`` span that carries what it counted, so a trace of
+        any window can sum it."""
+        added = self.counters.add(ticks=int(res.ticks),
+                                  units_run=int(res.units_run),
+                                  head_passes=int(res.head_passes),
+                                  decoded_tokens=int(np.sum(res.counts)))
+        with span("serve.collect", **added):
+            self._fold_result(res, slots, finished)
+
+    def _fold_result(self, res, slots: List[Optional[Request]],
+                     finished: List[Request]) -> None:
         """Fold one (possibly multi-tick) StepResult into the requests that
         occupied the slots WHEN THE MEGATICK WAS DISPATCHED: detokenize,
         per-tick exit stats, retire + compact. The snapshot matters in the
@@ -403,8 +418,9 @@ class ServingEngine:
         raised before the jit call donates the state) leaves the session
         intact, so a retry is safe."""
         if np.any(self.session.live_rows()):
-            handle = self._attempt(
-                "dispatch", lambda: self.session.step_async(self.megatick))
+            with span("serve.dispatch"):
+                handle = self._attempt(
+                    "dispatch", lambda: self.session.step_async(self.megatick))
             return handle, list(self.slots)
         return None
 
@@ -461,7 +477,8 @@ class ServingEngine:
                                "megatick finish wedged past watchdog")
             return
         t0 = time.monotonic()
-        res = self.session.finish_step(handle)
+        with span("serve.finish"):
+            res = self.session.finish_step(handle)
         dt = time.monotonic() - t0
         res, ok = self._checked(res)
         if not ok:
@@ -484,8 +501,10 @@ class ServingEngine:
             self._finish_handle(prev, finished)
 
     def _sync_step(self, finished: List[Request]) -> None:
-        res = self._attempt(
-            "dispatch", lambda: self.session.step(num_ticks=self.megatick))
+        with span("serve.dispatch"):
+            res = self._attempt(
+                "dispatch",
+                lambda: self.session.step(num_ticks=self.megatick))
         res, ok = self._checked(res)
         if not ok:
             self._recover_lost("nan_logits",
@@ -768,6 +787,7 @@ class ServingEngine:
             self.guard.uninstall()
 
     # ----- one batched engine tick -----
+    @spanned("serve.step")
     def step(self) -> List[Request]:
         """Scheduled admission (≤ one prefill chunk while decode is live),
         one strategy megatick for all live slots, retire + compact finished.
@@ -794,7 +814,11 @@ class ServingEngine:
             self._finish_handle(prev, finished)
         live = bool(np.any(self.session.live_rows()))
         free = [s for s in range(self.B) if self.slots[s] is None]
-        for ev in self.scheduler.tick(free, live_decode=live):
+        with span("serve.admit"):
+            admitted = self.scheduler.tick(free, live_decode=live)
+        self.counters.add(prefill_chunks=self.scheduler.last_tick_chunks,
+                          prefill_tokens=self.scheduler.last_tick_tokens)
+        for ev in admitted:
             req = self._inflight.pop(ev.uid)
             if req.max_new_tokens > 0:
                 self._admit_token(req, ev.first_token)

@@ -335,26 +335,97 @@ def test_paged_indirection_roundtrip_property():
 
 
 # ---------------- paged decode kernel ----------------
-def test_paged_decode_kernel_matches_ref():
+# (KV heads, query heads, pool dtype, row lengths, dead table entries alias
+# the trash page, int8 pools + scale pools, windows). Every case has B=3
+# rows of P=4 pages of 16 positions (capacity 64) in a pool with one extra
+# (trash) page.
+PAGED_KERNEL_CASES = {
+    "gqa2_shuffled": (2, 4, jnp.float32, (5, 33, 64), False, False,
+                      (None, 20)),
+    "mha_f32": (4, 4, jnp.float32, (0, 64, 37), False, False, (None,)),
+    "gqa3_f32": (2, 6, jnp.float32, (0, 64, 37), False, False, (None,)),
+    "mha_bf16": (4, 4, jnp.bfloat16, (0, 64, 37), False, False, (None,)),
+    "gqa3_bf16": (2, 6, jnp.bfloat16, (0, 64, 37), False, False, (None,)),
+    "trash_aliased": (2, 6, jnp.float32, (0, 17, 50), True, False,
+                      (None, 20)),
+    "int8_mha": (4, 4, jnp.float32, (0, 64, 37), True, True, (None,)),
+    "int8_gqa3": (2, 6, jnp.float32, (9, 64, 37), True, True, (None, 20)),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_KERNEL_CASES))
+def test_paged_decode_kernel_matches_ref(case):
     """Page-table-aware split-KV kernel (interpret mode) vs the
-    gather-then-dense-reference oracle, shuffled table + ragged lengths."""
+    gather-then-dense-reference oracle: shuffled table, ragged lengths (an
+    empty row, a row at capacity, a row ending mid-page), MHA and GQA, f32
+    and bf16 pools, dead table entries aliasing the trash page, and int8
+    pools + scale pools against the XLA ``kv_quant`` dequant of the same
+    codes."""
     from repro.kernels.decode_attention import ops as da_ops
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
-    B, S, KVH, H, hd, ps = 3, 64, 2, 4, 32, 16
-    NP = B * (S // ps) + 1
+    from repro.models.model import _kv_dequantize, _kv_quantize
+    KVH, H, dtype, lens, trash, int8, windows = PAGED_KERNEL_CASES[case]
+    B, S, hd, ps = 3, 64, 32, 16
+    P = S // ps
+    NP = B * P + 1
+    kp = jax.random.normal(jax.random.PRNGKey(0), (NP, ps, KVH, hd))
+    vp = jax.random.normal(jax.random.PRNGKey(1), (NP, ps, KVH, hd))
+    q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, H, hd)).astype(dtype)
+    table = jax.random.permutation(
+        jax.random.PRNGKey(3), NP - 1)[:B * P].reshape(B, P)
+    clen = jnp.array(lens, jnp.int32)
+    if trash:
+        live = (clen[:, None] + ps - 1) // ps > jnp.arange(P)[None]
+        table = jnp.where(live, table, NP - 1)
+    scales = {}
+    if int8:
+        (kp, ks), (vp, vs) = _kv_quantize(kp), _kv_quantize(vp)
+        scales = {"k_scale": ks, "v_scale": vs}
+        kr, vr = _kv_dequantize(kp, ks, dtype), _kv_dequantize(vp, vs, dtype)
+    else:
+        kp, vp = kp.astype(dtype), vp.astype(dtype)
+        kr, vr = kp, vp
+    # bf16 cases: the reference attends the same bf16 values in f32, and
+    # the kernel's output is rounded to bf16 (2^-8 relative at most)
+    tol = 2e-6 if dtype == jnp.float32 else 2.0 ** -8
+    empty = np.asarray(clen) == 0
+    for window in windows:
+        out = da_ops.paged_decode_attention(None, q, kp, vp, table, clen,
+                                            window=window, **scales)
+        ref = paged_decode_attention_ref(
+            q.astype(jnp.float32), kr.astype(jnp.float32),
+            vr.astype(jnp.float32), table, clen, window=window)
+        assert out.dtype == dtype
+        out = np.asarray(out, np.float32)
+        # a row with no key attends nothing: the l == 0 guard gives zeros
+        # (the reference's softmax over all-masked logits is uniform)
+        np.testing.assert_array_equal(out[empty], 0.0)
+        np.testing.assert_allclose(out[~empty], np.asarray(ref)[~empty],
+                                   atol=tol, rtol=tol)
+
+
+def test_paged_decode_kernel_head_groups(monkeypatch):
+    """Where one page of every head would not fit a step's VMEM, the kernel
+    splits the KV heads into groups, a grid axis; the result is the
+    reference's. The VMEM size is shrunk so that 16 f32 heads on 16-token
+    pages take two groups of 8."""
+    from repro.kernels.decode_attention import decode_attention as da
+    from repro.kernels.decode_attention.ref import paged_decode_attention_ref
+    B, P, KVH, H, hd, ps = 2, 3, 16, 32, 32, 16
+    monkeypatch.setattr(da, "_VMEM_BYTES", 2**17)
+    assert da._head_groups(ps, KVH, H // KVH, hd, jnp.float32, jnp.float32,
+                           False) == 2
+    NP = B * P + 1
     kp = jax.random.normal(jax.random.PRNGKey(0), (NP, ps, KVH, hd))
     vp = jax.random.normal(jax.random.PRNGKey(1), (NP, ps, KVH, hd))
     q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, H, hd))
     table = jax.random.permutation(
-        jax.random.PRNGKey(3), NP - 1)[:B * (S // ps)].reshape(B, S // ps)
-    clen = jnp.array([5, 33, 64], jnp.int32)
-    for window in (None, 20):
-        out = da_ops.paged_decode_attention(None, q, kp, vp, table, clen,
-                                            window=window)
-        ref = paged_decode_attention_ref(q, kp, vp, table, clen,
-                                         window=window)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-6, rtol=2e-6)
+        jax.random.PRNGKey(3), NP - 1)[:B * P].reshape(B, P)
+    clen = jnp.array([40, 7], jnp.int32)
+    out = da.paged_decode_attention_fwd(q, kp, vp, table, clen)
+    ref = paged_decode_attention_ref(q, kp, vp, table, clen)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6,
+                               rtol=2e-6)
 
 
 def test_paged_decode_kernel_end_to_end(setup):

@@ -159,20 +159,38 @@ def test_predictor_mlp_quantized(compile_for_chip, bits):
 
 
 @pytest.mark.parametrize("kv_int8", [False, True])
-def test_paged_decode_attention(compile_for_chip, kv_int8):
+@pytest.mark.parametrize("heads,kv_heads", [(HEADS, HEADS), (48, 4), (8, 8)],
+                         ids=["mha32", "gqa48_4", "tp4_shard8"])
+def test_paged_decode_attention(compile_for_chip, heads, kv_heads, kv_int8):
+    """Llama2-7B's MHA, StarCoder2-15B's GQA (48 query heads on 4 KV
+    heads) and one TP=4 shard of the MHA (8 local heads): each block
+    layout the served paths use, with bf16 or int8 + scale pools."""
     n_pages = B * PAGES_PER_ROW
-    pool = _s((n_pages, PAGE, HEADS, HEAD_DIM),
+    pool = _s((n_pages, PAGE, kv_heads, HEAD_DIM),
               jnp.int8 if kv_int8 else jnp.bfloat16)
-    scale = _s((n_pages, PAGE, HEADS), jnp.float32) if kv_int8 else None
+    scale = _s((n_pages, PAGE, kv_heads), jnp.float32) if kv_int8 else None
 
     def fn(q, k_pool, v_pool, table, lens, k_scale, v_scale):
         return paged_decode_attention_fwd(q, k_pool, v_pool, table, lens,
                                           k_scale=k_scale, v_scale=v_scale)
 
     _assert_kernel(compile_for_chip(
-        fn, _s((B, 1, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
+        fn, _s((B, 1, heads, HEAD_DIM), jnp.bfloat16), pool, pool,
         _s((B, PAGES_PER_ROW), jnp.int32), _s((B,), jnp.int32), scale,
         scale))
+
+
+def test_paged_decode_attention_head_groups(compile_for_chip):
+    """Pages too large for one step's VMEM split the KV heads into groups,
+    a grid axis: 32 bf16 heads on 512-token pages take two groups."""
+    from repro.kernels.decode_attention.decode_attention import _head_groups
+    page, pages_per_row = 512, 4
+    assert _head_groups(page, HEADS, 1, HEAD_DIM, jnp.bfloat16, jnp.bfloat16,
+                        False) == 2
+    pool = _s((B * pages_per_row, page, HEADS, HEAD_DIM), jnp.bfloat16)
+    _assert_kernel(compile_for_chip(
+        paged_decode_attention_fwd, _s((B, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+        pool, pool, _s((B, pages_per_row), jnp.int32), _s((B,), jnp.int32)))
 
 
 @pytest.mark.parametrize("strategy", ["specee", "tree"])
